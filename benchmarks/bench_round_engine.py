@@ -24,11 +24,10 @@ round path so the perf trajectory captures the kernel work:
                   engine+shuffle)
   pallas+iid      fed_gather + fed_local_sgd kernels (fp-tolerance parity)
 
-NOTE on this container: the kernels run in INTERPRET mode on CPU
-(ops.KERNEL_INTERPRET), where the pallas_call grid serialises the vmapped
-client axis — the recorded pallas rounds/s measure interpreter overhead,
-not the TPU win the kernels target.  The legs exist so the number is
-tracked honestly and flips to a real measurement on TPU hardware.
+The platform decides how the kernels run (repro.kernels.ops): compiled on a
+TPU, in the Pallas interpreter on any other backend, where the pallas_call
+grid serialises the vmapped client axis — a CPU recording of the pallas
+rounds/s measures interpreter overhead, not the TPU win the kernels target.
 
 Scan-driver legs (ISSUE 3, `RoundEngine.make_segment_fn`) time the fused
 multi-round path: BLOCK_SIZE rounds per jitted lax.scan — selection,
@@ -177,6 +176,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.hostdev import force_from_env  # noqa: E402
 
 # before jax initializes: lets --shards N time the sharded scan leg on a
@@ -830,6 +830,7 @@ def main():
                          "trajectory file")
     ap.add_argument("--out", default=OUT_PATH)
     args = ap.parse_args()
+    use_compile_cache()
     if args.gate_only and os.path.abspath(args.out) == \
             os.path.abspath(OUT_PATH):
         ap.error("--gate-only writes a partial record; pass --out elsewhere")

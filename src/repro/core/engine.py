@@ -94,8 +94,8 @@ compressor (``fed_compress``), and, iff the kernel-eligibility dispatch
 falls back to the XLA autodiff implementation for any stage with no
 applicable kernel (non-MCLR local steps, the seed-exact ``"shuffle"``
 minibatch rule, silo streams), so the flag is safe to flip on every
-scenario.  On CPU the kernels run in interpret mode
-(``repro.kernels.ops.KERNEL_INTERPRET``).
+scenario.  The platform decides how the kernels run: compiled on a TPU,
+interpreted elsewhere (``repro.kernels.ops``).
 
 Global params are donated to the round function (``donate_argnums=0``) so the
 update happens in place on accelerators; donation is skipped on CPU where XLA
@@ -1361,7 +1361,6 @@ class RoundEngine:
         from jax.sharding import PartitionSpec as P
 
         from repro.core.selection import cohort_overflow
-        from repro.sharding.rules import shard_map_unchecked
 
         core = self._shard_round_core(model, batch_size, max_iters, max_n,
                                       sampling, backend, capacity)
@@ -1389,8 +1388,8 @@ class RoundEngine:
                                        ids_, it_, rng_, res[0], cor)
                     return pk, ls, res[None]
 
-                params_k, losses, residual = shard_map_unchecked(
-                    shard_fn, mesh,
+                params_k, losses, residual = jax.shard_map(
+                    shard_fn, mesh=mesh, check_vma=False,
                     in_specs=(P(), P("data"), P("data"), P("data"),
                               P("data"), P(), P(), P(), P("data"), P()),
                     out_specs=(P(), P(), P("data")))(
@@ -1404,8 +1403,8 @@ class RoundEngine:
                                        ids_, it_, rng_, res[0])
                     return pk, ls, res[None]
 
-                params_k, losses, residual = shard_map_unchecked(
-                    shard_fn, mesh,
+                params_k, losses, residual = jax.shard_map(
+                    shard_fn, mesh=mesh, check_vma=False,
                     in_specs=(P(), P("data"), P("data"), P("data"),
                               P("data"), P(), P(), P(), P("data")),
                     out_specs=(P(), P(), P("data")))(
@@ -1416,8 +1415,8 @@ class RoundEngine:
                     return core(gp, x[0], y[0], offs[0], lens[0], ids_, it_,
                                 rng_)
 
-                params_k, losses = shard_map_unchecked(
-                    shard_fn, mesh,
+                params_k, losses = jax.shard_map(
+                    shard_fn, mesh=mesh, check_vma=False,
                     in_specs=(P(), P("data"), P("data"), P("data"),
                               P("data"), P(), P(), P()),
                     out_specs=(P(), P()))(
@@ -1947,7 +1946,6 @@ class RoundEngine:
         from repro.core.selection import (_cohort_scores, cohort_overflow,
                                           local_topk_candidates,
                                           merge_topk_candidates, pad_scores)
-        from repro.sharding.rules import shard_map_unchecked
 
         core = self._shard_round_core(model, batch_size, max_iters, max_n,
                                       sampling, backend, capacity)
@@ -2032,16 +2030,16 @@ class RoundEngine:
                 return jax.lax.scan(one_round, state, ts)
 
             if compressing:
-                state, residual, stats = shard_map_unchecked(
-                    shard_seg, mesh,
+                state, residual, stats = jax.shard_map(
+                    shard_seg, mesh=mesh, check_vma=False,
                     in_specs=(P(), P(), P("data"), P("data"), P("data"),
                               P("data"), P(), P(), P("data")),
                     out_specs=(P(), P("data"), P()))(
                     state, ts, flat_x, flat_y, offsets, lengths, mu, sigma,
                     residual)
                 return state, residual, stats
-            return shard_map_unchecked(
-                shard_seg, mesh,
+            return jax.shard_map(
+                shard_seg, mesh=mesh, check_vma=False,
                 in_specs=(P(), P(), P("data"), P("data"), P("data"),
                           P("data"), P(), P()),
                 out_specs=(P(), P()))(

@@ -893,6 +893,12 @@ class FedSAEServer:
             state["q_fail"] = jnp.asarray(self.q_fail, jnp.int32)
             state["q_try"] = jnp.asarray(self.q_try, jnp.int32)
             state["q_susp"] = jnp.asarray(self.q_susp, jnp.int32)
+        if self.mesh is not None:
+            # the sharded segment returns its carry replicated over the
+            # mesh; a carry placed any other way compiles the segment again
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+            state = jax.device_put(state, NamedSharding(self.mesh, P()))
         return state
 
     def _absorb_state(self, state: Dict):

@@ -27,6 +27,22 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+# Every client's first row in the packed arrays is a multiple of this: the
+# f32 sublane tiling of a TPU, so the fed_gather kernel's per-client window
+# starts on a tile boundary (kernels/fed_gather.py).
+ROW_ALIGN = 8
+
+
+def _align(n: int) -> int:
+    return -(-n // ROW_ALIGN) * ROW_ALIGN
+
+
+def _aligned_offsets(ns: np.ndarray) -> np.ndarray:
+    """Row offsets of clients packed back to back, each rounded up to
+    ``ROW_ALIGN`` (the gap rows are zeros)."""
+    ends = np.cumsum([_align(int(n)) for n in ns], dtype=np.int64)
+    return np.concatenate([[0], ends[:-1]]).astype(np.int64)
+
 
 @dataclasses.dataclass
 class PackedClients:
@@ -38,25 +54,27 @@ class PackedClients:
     round moves O(K) ids host->device instead of O(K * max_n * feature_dim)
     restacked padded samples.
 
-    ``x``/``y`` carry ``max_n`` zero rows of tail slack past the last
-    client's samples, so every client's ``[offset, offset + max_n)`` window
-    is in bounds — the contract the Pallas ``fed_gather`` kernel DMAs
-    against (kernels/fed_gather.py).  The slack rows are masked out of every
-    statistic like any other padding.
+    Each client starts on a ``ROW_ALIGN``-row boundary (zero rows fill the
+    gaps), and ``x``/``y`` carry ``max_n`` rounded up to ``ROW_ALIGN`` zero
+    rows of tail slack past the last client, so every client's aligned
+    ``[offset, offset + max_n)`` window is in bounds — the contract the
+    Pallas ``fed_gather`` kernel DMAs against (kernels/fed_gather.py).  Gap
+    and slack rows are masked out of every statistic like any other
+    padding.
 
     Sharded layout (ISSUE 4, ``packed(shards=S)``): every array gains a
     leading shard axis that maps onto the ``data`` mesh axis.  Shard ``s``
     owns the contiguous client block ``[s * C, (s + 1) * C)`` where
     ``C = clients_per_shard``, so global client ``g`` lives on shard
     ``g // C`` at local row ``g % C``.  Each shard's flat arrays hold only
-    its own clients' samples (plus the same ``max_n`` tail-slack contract,
-    per shard, then zero-padding up to a common length so the shards
+    its own clients' samples (with the same alignment and tail slack, per
+    shard, then zero-padding up to a common length so the shards
     stack); ``offsets`` are shard-local.  The last shard may own ghost
     clients (``lengths == 0``) when S does not divide the population —
     ghosts are never selected and gather nothing.
     """
-    x: object         # jnp [total + max_n, ...feat]  (sharded: [S, L, ...])
-    y: object         # jnp [total + max_n] int32     (sharded: [S, L])
+    x: object         # jnp [rows, ...feat]  (sharded: [S, L, ...])
+    y: object         # jnp [rows] int32     (sharded: [S, L])
     offsets: object   # jnp [n_clients] int32         (sharded: [S, C], local)
     lengths: object   # jnp [n_clients] int32         (sharded: [S, C])
     max_n: int        # cohort shard width; consumed by make_packed_round
@@ -151,15 +169,16 @@ class FederatedDataset:
         m = int(max_n or ns.max())
         if shards:
             return self._packed_sharded(int(shards), m)
-        offsets = np.zeros(len(ns), np.int64)
-        np.cumsum(ns[:-1], out=offsets[1:])
-        # max_n rows of tail slack: every per-client [offset, offset+max_n)
+        offsets = _aligned_offsets(ns)
+        # aligned tail slack: every per-client [offset, offset+max_n)
         # window stays in bounds (the fed_gather DMA contract)
-        pad_x = np.zeros((m,) + self.clients_x[0].shape[1:],
-                         self.clients_x[0].dtype)
-        x = np.concatenate(self.clients_x + [pad_x], axis=0)
-        y = np.concatenate(self.clients_y + [np.zeros(m, np.int32)],
-                           axis=0).astype(np.int32)
+        rows = int(offsets[-1]) + _align(int(ns[-1])) + _align(m)
+        x = np.zeros((rows,) + self.clients_x[0].shape[1:],
+                     self.clients_x[0].dtype)
+        y = np.zeros(rows, np.int32)
+        for off, cx, cy in zip(offsets, self.clients_x, self.clients_y):
+            x[off:off + len(cy)] = cx
+            y[off:off + len(cy)] = cy
         return PackedClients(
             x=jnp.asarray(x), y=jnp.asarray(y),
             offsets=jnp.asarray(offsets, jnp.int32),
@@ -176,23 +195,25 @@ class FederatedDataset:
         ns = self.sizes
         feat = self.clients_x[0].shape[1:]
         dtype = self.clients_x[0].dtype
-        # common flat length: widest shard's samples + max_n tail slack
+        # common flat length: widest shard's aligned samples + tail slack
         blocks = [list(range(s * C, min((s + 1) * C, N)))
                   for s in range(shards)]
-        L = max((int(ns[b].sum()) if b else 0) for b in blocks) + max_n
+        L = max(sum(_align(int(ns[g])) for g in b) for b in blocks) \
+            + _align(max_n)
         x = np.zeros((shards, L) + feat, dtype)
         y = np.zeros((shards, L), np.int32)
         offsets = np.zeros((shards, C), np.int32)
         lengths = np.zeros((shards, C), np.int32)
         for s, block in enumerate(blocks):
-            pos = 0
-            for j, g in enumerate(block):
+            if not block:
+                continue
+            offs = _aligned_offsets(ns[block])
+            for j, (g, pos) in enumerate(zip(block, offs)):
                 n = len(self.clients_y[g])
                 offsets[s, j] = pos
                 lengths[s, j] = n
                 x[s, pos:pos + n] = self.clients_x[g]
                 y[s, pos:pos + n] = self.clients_y[g]
-                pos += n
         return PackedClients(
             x=jnp.asarray(x), y=jnp.asarray(y),
             offsets=jnp.asarray(offsets), lengths=jnp.asarray(lengths),

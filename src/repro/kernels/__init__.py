@@ -3,9 +3,9 @@
 Layout convention: one ``<name>.py`` per kernel family (the raw
 ``pallas_call`` machinery), ``ops.py`` for the jit'd public entry points,
 ``ref.py`` for the pure-jnp oracles every kernel is validated against.
-Everything runs with ``interpret=True`` in this CPU container; on a real
-TPU the identical ``pallas_call``s lower to Mosaic
-(``ops.KERNEL_INTERPRET``).
+The platform decides how a kernel runs: on a TPU the ``ops`` wrappers
+compile every ``pallas_call`` with Mosaic, on any other backend they run it
+in the Pallas interpreter (chosen at trace time, see ``ops``).
 
 Model kernels (custom_vjp, backward recomputes through the oracle):
 

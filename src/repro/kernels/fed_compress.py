@@ -25,6 +25,10 @@ order):
                                        parity across calling contexts)
     thr   = sort(a)[P - k]             k-th largest magnitude (k static)
     mask  = (a > thr) | earliest (a == thr) ties up to exactly k coords
+
+(the kernel finds ``thr`` and the tie cut-off by counting binary searches,
+since Mosaic lowers neither ``sort`` nor ``cumsum``; the result is the
+same bits).
     q     = clip(round(ef / scale), -127, 127) on the mask, else 0
 
 ``k == 0`` transmits nothing (empty mask); ``k == P`` keeps every
@@ -35,9 +39,9 @@ identity is EXACT in float32 (Sterbenz: each selected coordinate and its
 dequantized value are within a factor of two, so the subtraction is exact
 — tests/test_compression.py proves it property-based).
 
-Validated bitwise against kernels/ref.py with interpret=True on CPU; on
-TPU the same pallas_call lowers to Mosaic (the rowwise ``sort``/``cumsum``
-are the only non-elementwise ops and stay within one [1, P] VMEM tile).
+The platform decides how it runs: compiled by Mosaic on a TPU, interpreted
+elsewhere (``repro.kernels.ops``); the ``interpret`` argument forces
+either.  Validated bitwise against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -49,8 +53,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _count(pred) -> jax.Array:
+    return jnp.sum(pred.astype(jnp.int32))
+
+
 def _compress_kernel(ef_ref, q_ref, scale_ref, *, k: int):
-    e = ef_ref[...].astype(jnp.float32)            # [1, P]
+    e = ef_ref[0].astype(jnp.float32)              # [1, P]
     P = e.shape[1]
     a = jnp.abs(e)
     amax = jnp.max(a)
@@ -61,18 +69,35 @@ def _compress_kernel(ef_ref, q_ref, scale_ref, *, k: int):
     elif k >= P:
         mask = jnp.ones(e.shape, bool)
     else:
-        thr = jnp.sort(a, axis=-1)[0, P - k]
-        gt = a > thr
-        eq = a == thr
-        # exactly k coordinates: all strictly-above plus the EARLIEST ties
-        need = k - jnp.sum(gt.astype(jnp.int32))
-        take = eq & (jnp.cumsum(eq.astype(jnp.int32), axis=-1) <= need)
-        mask = gt | take
+        # k-th largest |e| without a sort: the int32 bit pattern of a
+        # non-negative float orders like the float, so build the largest
+        # pattern t with count(bits >= t) >= k one bit at a time (31 passes)
+        bits = jax.lax.bitcast_convert_type(a, jnp.int32)
+        t = jnp.int32(0)
+        for bit in range(30, -1, -1):
+            cand = t | jnp.int32(1 << bit)
+            t = jnp.where(_count(bits >= cand) >= k, cand, t)
+        # compared as patterns (equal to the float compares; a row holding
+        # a NaN has a NaN scale and quantizes to zero on either path)
+        gt = bits > t
+        eq = bits == t
+        # exactly k coordinates: all strictly-above plus the EARLIEST ties.
+        # Without a cumsum: c = the largest position with fewer than
+        # ``need`` ties before it (binary search over positions); the ties
+        # at positions <= c are the first ``need`` ones.
+        need = k - _count(gt)
+        pos = jax.lax.broadcasted_iota(jnp.int32, e.shape, 1)
+        c = jnp.int32(0)
+        for bit in range(P.bit_length() - 1, -1, -1):
+            cand = c + jnp.int32(1 << bit)
+            c = jnp.where((cand <= P) & (_count(eq & (pos < cand)) < need),
+                          cand, c)
+        mask = gt | (eq & (pos <= c))
     q = jnp.where(mask & (scale > 0),
                   jnp.clip(jnp.round(e / safe), -127.0, 127.0),
                   jnp.float32(0.0)).astype(jnp.int8)
-    q_ref[...] = q
-    scale_ref[0, 0] = scale
+    q_ref[0] = q
+    scale_ref[...] = jnp.full(scale_ref.shape, scale)
 
 
 def fed_compress_topk_q8_fwd(ef, *, k: int, interpret: bool = True):
@@ -84,19 +109,19 @@ def fed_compress_topk_q8_fwd(ef, *, k: int, interpret: bool = True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(K,),
-        in_specs=[pl.BlockSpec((1, P), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, 1, P), lambda i: (i, 0, 0))],
         out_specs=[
-            pl.BlockSpec((1, P), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, P), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),
         ],
     )
     q, scale = pl.pallas_call(
         functools.partial(_compress_kernel, k=int(k)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((K, P), jnp.int8),
-            jax.ShapeDtypeStruct((K, 1), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, P), jnp.int8),
+            jax.ShapeDtypeStruct((K, 1, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(ef)
-    return q, scale[:, 0]
+    )(ef.reshape(K, 1, P))
+    return q.reshape(K, P), scale[:, 0, 0]

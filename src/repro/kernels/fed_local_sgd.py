@@ -25,8 +25,9 @@ MXU-shaped on TPU.  Remaining divergence from the XLA path is reduction
 order inside matmuls/reductions, so parity holds to fp tolerance (see
 tests/test_fed_kernels.py), not bitwise.
 
-Validated against kernels/ref.py with interpret=True on CPU; on TPU the
-same pallas_call lowers to Mosaic.
+The platform decides how it runs: compiled by Mosaic on a TPU, interpreted
+elsewhere (``repro.kernels.ops``); the ``interpret`` argument forces
+either.  Validated against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -50,7 +51,7 @@ def _sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref, w0_ref, b0_ref,
     b_s[...] = b0_ref[...].astype(jnp.float32)
     x = x_ref[0].astype(jnp.float32)                       # [max_n, d]
     # one-hot labels for the whole shard (batch rows pick from it exactly)
-    oy = (y_ref[...].reshape(max_n, 1)
+    oy = (y_ref[0].reshape(max_n, 1)
           == jax.lax.broadcasted_iota(jnp.int32, (max_n, C), 1)
           ).astype(jnp.float32)                            # [max_n, C]
     npos = jax.lax.broadcasted_iota(jnp.int32, (B, max_n), 1)
@@ -91,9 +92,9 @@ def _sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref, w0_ref, b0_ref,
     loss_sum, cnt = jax.lax.fori_loop(
         0, max_iters, body, (jnp.float32(0.0), jnp.float32(0.0)))
     w_ref[0] = w_s[...].astype(w_ref.dtype)
-    b_ref[...] = b_s[...].astype(b_ref.dtype)
+    b_ref[0] = b_s[...].astype(b_ref.dtype)
     # iid loss semantics: mean minibatch loss over executed iterations
-    loss_ref[0, 0] = loss_sum / jnp.maximum(cnt, 1.0)
+    loss_ref[...] = jnp.full(loss_ref.shape, loss_sum / jnp.maximum(cnt, 1.0))
 
 
 def fed_local_sgd_mclr_fwd(x, y, idx, w0, b0, ns, n_iters, *, lr: float,
@@ -110,15 +111,15 @@ def fed_local_sgd_mclr_fwd(x, y, idx, w0, b0, ns, n_iters, *, lr: float,
         grid=(K,),
         in_specs=[
             pl.BlockSpec((1, max_n, d), lambda k, *_: (k, 0, 0)),
-            pl.BlockSpec((1, max_n), lambda k, *_: (k, 0)),
+            pl.BlockSpec((1, 1, max_n), lambda k, *_: (k, 0, 0)),
             pl.BlockSpec((1, max_iters, B), lambda k, *_: (k, 0, 0)),
             pl.BlockSpec((d, C), lambda k, *_: (0, 0)),
             pl.BlockSpec((1, C), lambda k, *_: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, d, C), lambda k, *_: (k, 0, 0)),
-            pl.BlockSpec((1, C), lambda k, *_: (k, 0)),
-            pl.BlockSpec((1, 1), lambda k, *_: (k, 0)),
+            pl.BlockSpec((1, 1, C), lambda k, *_: (k, 0, 0)),
+            pl.BlockSpec((1, 1, 128), lambda k, *_: (k, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((d, C), jnp.float32),
                         pltpu.VMEM((1, C), jnp.float32)],
@@ -129,9 +130,9 @@ def fed_local_sgd_mclr_fwd(x, y, idx, w0, b0, ns, n_iters, *, lr: float,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((K, d, C), w0.dtype),
-            jax.ShapeDtypeStruct((K, C), b0.dtype),
-            jax.ShapeDtypeStruct((K, 1), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, C), b0.dtype),
+            jax.ShapeDtypeStruct((K, 1, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(ns, n_iters, x, y, idx, w0, b0.reshape(1, C))
-    return w_k, b_k, losses[:, 0]
+    )(ns, n_iters, x, y.reshape(K, 1, max_n), idx, w0, b0.reshape(1, C))
+    return w_k, b_k.reshape(K, C), losses[:, 0, 0]

@@ -27,8 +27,9 @@ gradients, so engine-level parity is to fp tolerance; kernel/ref parity
 against ``ref.fed_local_sgd_dense`` is the pinned contract
 (tests/test_fused_generic.py).
 
-Validated with interpret=True on CPU; on TPU the same pallas_call lowers to
-Mosaic.
+The platform decides how it runs: compiled by Mosaic on a TPU, interpreted
+elsewhere (``repro.kernels.ops``); the ``interpret`` argument forces
+either.
 """
 from __future__ import annotations
 
@@ -55,7 +56,7 @@ def _dense_sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref,
     w2_s[...] = w20_ref[...].astype(jnp.float32)
     b2_s[...] = b20_ref[...].astype(jnp.float32)
     x = x_ref[0].astype(jnp.float32)                       # [max_n, d]
-    oy = (y_ref[...].reshape(max_n, 1)
+    oy = (y_ref[0].reshape(max_n, 1)
           == jax.lax.broadcasted_iota(jnp.int32, (max_n, C), 1)
           ).astype(jnp.float32)                            # [max_n, C]
     npos = jax.lax.broadcasted_iota(jnp.int32, (B, max_n), 1)
@@ -109,11 +110,11 @@ def _dense_sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref,
     loss_sum, cnt = jax.lax.fori_loop(
         0, max_iters, body, (jnp.float32(0.0), jnp.float32(0.0)))
     w1_ref[0] = w1_s[...].astype(w1_ref.dtype)
-    b1_ref[...] = b1_s[...].astype(b1_ref.dtype)
+    b1_ref[0] = b1_s[...].astype(b1_ref.dtype)
     w2_ref[0] = w2_s[...].astype(w2_ref.dtype)
-    b2_ref[...] = b2_s[...].astype(b2_ref.dtype)
+    b2_ref[0] = b2_s[...].astype(b2_ref.dtype)
     # iid loss semantics: mean minibatch loss over executed iterations
-    loss_ref[0, 0] = loss_sum / jnp.maximum(cnt, 1.0)
+    loss_ref[...] = jnp.full(loss_ref.shape, loss_sum / jnp.maximum(cnt, 1.0))
 
 
 def fed_local_sgd_dense_fwd(x, y, idx, w1, b1, w2, b2, ns, n_iters, *,
@@ -132,7 +133,7 @@ def fed_local_sgd_dense_fwd(x, y, idx, w1, b1, w2, b2, ns, n_iters, *,
         grid=(K,),
         in_specs=[
             pl.BlockSpec((1, max_n, d), lambda k, *_: (k, 0, 0)),
-            pl.BlockSpec((1, max_n), lambda k, *_: (k, 0)),
+            pl.BlockSpec((1, 1, max_n), lambda k, *_: (k, 0, 0)),
             pl.BlockSpec((1, max_iters, B), lambda k, *_: (k, 0, 0)),
             pl.BlockSpec((d, H), lambda k, *_: (0, 0)),
             pl.BlockSpec((1, H), lambda k, *_: (0, 0)),
@@ -141,10 +142,10 @@ def fed_local_sgd_dense_fwd(x, y, idx, w1, b1, w2, b2, ns, n_iters, *,
         ],
         out_specs=[
             pl.BlockSpec((1, d, H), lambda k, *_: (k, 0, 0)),
-            pl.BlockSpec((1, H), lambda k, *_: (k, 0)),
+            pl.BlockSpec((1, 1, H), lambda k, *_: (k, 0, 0)),
             pl.BlockSpec((1, H, C), lambda k, *_: (k, 0, 0)),
-            pl.BlockSpec((1, C), lambda k, *_: (k, 0)),
-            pl.BlockSpec((1, 1), lambda k, *_: (k, 0)),
+            pl.BlockSpec((1, 1, C), lambda k, *_: (k, 0, 0)),
+            pl.BlockSpec((1, 1, 128), lambda k, *_: (k, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((d, H), jnp.float32),
                         pltpu.VMEM((1, H), jnp.float32),
@@ -157,11 +158,13 @@ def fed_local_sgd_dense_fwd(x, y, idx, w1, b1, w2, b2, ns, n_iters, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((K, d, H), w1.dtype),
-            jax.ShapeDtypeStruct((K, H), b1.dtype),
+            jax.ShapeDtypeStruct((K, 1, H), b1.dtype),
             jax.ShapeDtypeStruct((K, H, C), w2.dtype),
-            jax.ShapeDtypeStruct((K, C), b2.dtype),
-            jax.ShapeDtypeStruct((K, 1), jnp.float32),
+            jax.ShapeDtypeStruct((K, 1, C), b2.dtype),
+            jax.ShapeDtypeStruct((K, 1, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(ns, n_iters, x, y, idx, w1, b1.reshape(1, H), w2, b2.reshape(1, C))
-    return w1_k, b1_k, w2_k, b2_k, losses[:, 0]
+    )(ns, n_iters, x, y.reshape(K, 1, max_n), idx, w1, b1.reshape(1, H),
+      w2, b2.reshape(1, C))
+    return (w1_k, b1_k.reshape(K, H), w2_k, b2_k.reshape(K, C),
+            losses[:, 0, 0])

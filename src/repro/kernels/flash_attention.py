@@ -6,8 +6,8 @@ sequential on TPU, so the online-softmax state (m, l, acc) lives in VMEM
 scratch that persists across kv steps.  Block shapes are MXU-aligned
 (multiples of 128 where the problem allows).
 
-Validated against kernels/ref.py with interpret=True on CPU; on TPU the
-same pallas_call lowers to Mosaic.
+The platform decides how it runs: compiled by Mosaic on a TPU, interpreted
+elsewhere (``repro.kernels.ops``).  Validated against kernels/ref.py.
 """
 from __future__ import annotations
 

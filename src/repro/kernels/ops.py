@@ -4,8 +4,12 @@ Each model op is a custom_vjp: the forward runs the Pallas kernel, the
 backward recomputes through the jnp oracle (flash-style recompute — the
 standard memory/compute trade on TPU).  The federated ops at the bottom are
 forward-only (round functions are not differentiated through).
-``interpret=True`` everywhere in this container (CPU); on a real TPU pass
-interpret=False via KERNEL_INTERPRET.
+
+The platform decides how a kernel runs: on a TPU every ``pallas_call`` is
+compiled by Mosaic, on any other backend it runs in the Pallas
+interpreter.  The choice is made when a wrapper is traced, never at import
+(``_interpret``); the ``*_fwd`` functions keep an explicit ``interpret=``
+argument so tests can force either mode.
 """
 from __future__ import annotations
 
@@ -25,7 +29,12 @@ from repro.kernels.fused_xent import fused_softmax_xent_fwd
 from repro.kernels.selective_scan import selective_scan_fwd
 from repro.obs.profiling import annotate
 
-KERNEL_INTERPRET = True  # CPU container: interpret mode; False on real TPU
+
+def _interpret() -> bool:
+    """Interpret unless the default backend is a TPU.  Called at trace time:
+    reading the backend at import would initialise it before callers (and
+    the test harness) have chosen their devices."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -36,20 +45,20 @@ KERNEL_INTERPRET = True  # CPU container: interpret mode; False on real TPU
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0):
     out, _ = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                 interpret=KERNEL_INTERPRET)
+                                 interpret=_interpret())
     return out
 
 
 def _fa_fwd(q, k, v, causal, window):
     out, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                   interpret=KERNEL_INTERPRET)
+                                   interpret=_interpret())
     return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(causal, window, res, g):
     q, k, v, out, lse = res
     return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                               window=window, interpret=KERNEL_INTERPRET)
+                               window=window, interpret=_interpret())
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -63,7 +72,7 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 @jax.custom_vjp
 def selective_scan(dt, A, Bmat, Cmat, x, h0):
     return selective_scan_fwd(dt, A, Bmat, Cmat, x, h0,
-                              interpret=KERNEL_INTERPRET)
+                              interpret=_interpret())
 
 
 def _ss_fwd(dt, A, Bmat, Cmat, x, h0):
@@ -85,7 +94,7 @@ selective_scan.defvjp(_ss_fwd, _ss_bwd)
 
 @jax.custom_vjp
 def fused_softmax_xent(h, W, labels):
-    return fused_softmax_xent_fwd(h, W, labels, interpret=KERNEL_INTERPRET)
+    return fused_softmax_xent_fwd(h, W, labels, interpret=_interpret())
 
 
 def _fx_fwd(h, W, labels):
@@ -121,10 +130,10 @@ fused_softmax_xent.defvjp(_fx_fwd, _fx_bwd)
 def fed_cohort_gather(flat_x, flat_y, starts, ns, max_n: int):
     """Fused gather+mask over the packed federation (see fed_gather.py).
 
-    flat_x/flat_y must carry >= max_n rows of tail slack after the last
-    client's samples (FederatedDataset.packed pads at upload)."""
+    Every start must be 8-row aligned with an aligned ``max_n`` window in
+    bounds (FederatedDataset.packed lays clients out so at upload)."""
     return fed_cohort_gather_fwd(flat_x, flat_y, starts, ns, max_n=max_n,
-                                 interpret=KERNEL_INTERPRET)
+                                 interpret=_interpret())
 
 
 @annotate("fed.local_sgd.pallas")
@@ -135,7 +144,7 @@ def fed_local_sgd_mclr(x, y, idx, w0, b0, ns, n_iters, lr: float,
     Returns (w_k [K, d, C], b_k [K, C], losses [K])."""
     return fed_local_sgd_mclr_fwd(x, y, idx, w0, b0, ns, n_iters, lr=lr,
                                   prox_mu=prox_mu,
-                                  interpret=KERNEL_INTERPRET)
+                                  interpret=_interpret())
 
 
 @annotate("fed.local_sgd_dense.pallas")
@@ -147,7 +156,7 @@ def fed_local_sgd_dense(x, y, idx, w1, b1, w2, b2, ns, n_iters, lr: float,
     losses [K])."""
     return fed_local_sgd_dense_fwd(x, y, idx, w1, b1, w2, b2, ns, n_iters,
                                    lr=lr, prox_mu=prox_mu,
-                                   interpret=KERNEL_INTERPRET)
+                                   interpret=_interpret())
 
 
 # the step families a fused local-SGD kernel exists for, by LocalStep.kind
@@ -177,4 +186,4 @@ def fed_compress_topk_q8(ef, k: int):
     delta rows (see fed_compress.py).  Bitwise-identical to the ref twin.
 
     Returns (q [K, P] int8, scale [K] f32); transmitted value = q * scale."""
-    return fed_compress_topk_q8_fwd(ef, k=k, interpret=KERNEL_INTERPRET)
+    return fed_compress_topk_q8_fwd(ef, k=k, interpret=_interpret())

@@ -6,7 +6,8 @@ in VMEM scratch and persists across chunks, so the [B, S, d, N] state tensor
 never exists in HBM.  dA = exp(dt*A) and dB*x are computed in-register per
 timestep from the compact (dt, A, B, x) inputs.
 
-Validated against kernels/ref.py (interpret=True).
+The platform decides how it runs: compiled by Mosaic on a TPU, interpreted
+elsewhere (``repro.kernels.ops``).  Validated against kernels/ref.py.
 """
 from __future__ import annotations
 

@@ -26,7 +26,7 @@ import jax
 
 from repro.configs import (ARCH_IDS, INPUT_SHAPES, get_config, get_shape,
                            supported_shapes)
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import lower_step
 from repro.models.api import build_model
 from repro.roofline.analysis import model_flops_estimate, roofline_terms
@@ -95,7 +95,7 @@ def run_one(arch: str, shape_id: str, multi_pod: bool = False,
     model = build_model(cfg)
 
     t0 = time.time()
-    with set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         lowered, kind = lower_step(model, shape, mesh, optimizer)
         t_lower = time.time() - t0
         compiled = lowered.compile()

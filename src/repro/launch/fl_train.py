@@ -23,6 +23,7 @@ import argparse
 import json
 import os
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.hostdev import force_from_env
 
 # before the jax backend initializes: lets --shards N run on a simulated
@@ -254,7 +255,8 @@ def main():
                     help="round compute backend: pallas runs the fused "
                          "cohort-gather / local-SGD kernels (repro.kernels), "
                          "falling back to XLA for stages with no kernel; "
-                         "interpret mode on CPU")
+                         "the platform decides how they run: compiled on "
+                         "a TPU, interpreted elsewhere")
     ap.add_argument("--driver", default="host", choices=("host", "scan"),
                     help="round loop driver: host runs one python iteration "
                          "per round (bitwise seed-compatible); scan fuses "
@@ -377,6 +379,7 @@ def main():
     ap.add_argument("--silos", type=int, default=4)
     ap.add_argument("--max-steps", type=int, default=8)
     args = ap.parse_args()
+    use_compile_cache()
     if args.silo_arch:
         run_silo(args)
     else:

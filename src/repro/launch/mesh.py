@@ -1,31 +1,13 @@
-"""Production meshes (defined as functions so importing this module never
-touches jax device state) + compat shims spanning old/new JAX.
-
-JAX 0.4.x has neither ``jax.sharding.AxisType`` (explicit-sharding axis
-types) nor ``jax.set_mesh``; both arrived with the explicit-sharding API.
-``_make_mesh`` passes ``axis_types`` only when available, and ``set_mesh``
-falls back to the ambient-mesh context manager (a ``Mesh`` is its own
-context manager on every JAX version we support).
-"""
+"""Production meshes, defined as functions so importing this module never
+touches jax device state."""
 from __future__ import annotations
 
 import jax
 
 
 def _make_mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` where it exists; the mesh's own ambient context
-    manager otherwise.  Use as ``with set_mesh(mesh): ...``."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

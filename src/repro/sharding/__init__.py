@@ -3,6 +3,5 @@ from repro.sharding.rules import (  # noqa: F401
     current_rules,
     logical_spec,
     shard,
-    shard_map_unchecked,
     use_rules,
 )

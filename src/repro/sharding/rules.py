@@ -74,11 +74,8 @@ def use_rules(rules: Rules):
 
 
 def _abstract_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if m is None or not m.axis_names:
+    m = jax.sharding.get_abstract_mesh()
+    if not m.axis_names:
         return None
     return m
 
@@ -89,9 +86,8 @@ def logical_spec(shape: Sequence[int], axes: Sequence[Optional[str]],
     or absent mesh axes.
 
     ``mesh`` may be a concrete ``jax.sharding.Mesh`` (same ``axis_names`` /
-    ``shape`` interface as the abstract mesh) — required on JAX versions
-    without ``get_abstract_mesh``, where the ambient lookup returns None and
-    the annotations would otherwise silently degrade to replicated."""
+    ``shape`` interface as the abstract mesh) for callers outside a
+    ``jax.set_mesh`` context; without one the ambient mesh is used."""
     rules = rules or current_rules()
     mesh = mesh if mesh is not None else _abstract_mesh()
     if mesh is None:
@@ -131,25 +127,3 @@ def shard(x: jax.Array, *axes: Optional[str]):
         raise ValueError(f"shard(): {len(axes)} axes for rank-{x.ndim} tensor")
     spec = logical_spec(x.shape, axes)
     return jax.lax.with_sharding_constraint(x, spec)
-
-
-def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across JAX versions.
-
-    The federated engine's sharded rounds (ISSUE 4) return psum-reduced
-    (hence replicated) values through ``out_specs=P()``; the static
-    replication checker predates some of the collectives' rules on older
-    JAX, so it is disabled uniformly.  Newer JAX renamed the toggle
-    (check_rep -> check_vma) and promoted shard_map out of experimental —
-    try the modern spelling first, fall back per-version.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)

@@ -59,12 +59,13 @@ def _tree_close(a, b, rtol=RTOL, atol=ATOL):
 
 def test_gather_matches_ref_including_ragged_edges():
     """Window + mask parity with the jnp oracle, covering length == 0,
-    length == max_n and interior clients."""
+    length == max_n and interior clients (starts 8-row aligned, as the
+    packed layout places them)."""
     rng = np.random.default_rng(0)
     max_n, d = 8, 5
-    flat = jnp.asarray(rng.normal(size=(30 + max_n, d)), jnp.float32)
-    flat_y = jnp.asarray(rng.integers(0, 4, 30 + max_n), jnp.int32)
-    starts = jnp.asarray([0, 4, 12, 20, 30], jnp.int32)
+    flat = jnp.asarray(rng.normal(size=(32 + max_n, d)), jnp.float32)
+    flat_y = jnp.asarray(rng.integers(0, 4, 32 + max_n), jnp.int32)
+    starts = jnp.asarray([0, 8, 16, 24, 32], jnp.int32)
     ns = jnp.asarray([4, 8, 0, 6, 0], jnp.int32)   # max_n, zero-length edges
     x, y, mask = ops.fed_cohort_gather(flat, flat_y, starts, ns, max_n)
     xr, yr, mr = ref.fed_cohort_gather(flat, flat_y, starts, ns, max_n=max_n)
@@ -101,13 +102,14 @@ def test_gather_handles_higher_rank_features():
     kernel and come back in their original feature shape."""
     rng = np.random.default_rng(1)
     max_n = 4
-    flat = jnp.asarray(rng.integers(0, 99, (10 + max_n, 3, 2)), jnp.int32)
-    flat_y = jnp.asarray(rng.integers(0, 2, 10 + max_n), jnp.int32)
-    starts = jnp.asarray([0, 6], jnp.int32)
+    flat = jnp.asarray(rng.integers(0, 99, (16, 3, 2)), jnp.int32)
+    flat_y = jnp.asarray(rng.integers(0, 2, 16), jnp.int32)
+    starts = jnp.asarray([0, 8], jnp.int32)
     ns = jnp.asarray([4, 3], jnp.int32)
     x, y, mask = ops.fed_cohort_gather(flat, flat_y, starts, ns, max_n)
     assert x.shape == (2, max_n, 3, 2)
     np.testing.assert_array_equal(np.asarray(x)[0], np.asarray(flat)[0:4])
+    np.testing.assert_array_equal(np.asarray(x)[1], np.asarray(flat)[8:12])
 
 
 # ---------------------------------------------------------------------------

@@ -166,8 +166,7 @@ def test_scan_driver_runs_under_x64(small_fed):
     """jax_enable_x64 must not widen the scan carry: L/H/theta/values stay
     pinned float32 and the driver still runs end to end."""
     ds, model = small_fed
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         scan = _server(ds, model, "scan")
         assert all(dt == jnp.float32
                    for dt in _state_dtypes(scan).values())
@@ -181,7 +180,6 @@ def test_prediction_device_parity_under_x64():
     """The float32 twins agree with the float64 numpy originals to 1e-6
     regardless of the x64 flag (satellite: explicit scan-state dtypes)."""
     from repro.core import prediction as pred
-    from jax.experimental import enable_x64
     rng = np.random.default_rng(3)
     L = rng.uniform(0.5, 10.0, 64).astype(np.float32)
     H = (L + rng.uniform(0.1, 10.0, 64)).astype(np.float32)
@@ -197,7 +195,7 @@ def test_prediction_device_parity_under_x64():
         assert np.asarray(L2d).dtype == np.float32
 
     check()
-    with enable_x64():
+    with jax.enable_x64(True):
         check()
 
 

@@ -201,9 +201,8 @@ def test_logical_spec_off_mesh_is_empty():
 
 
 def test_rules_drop_nondivisible_axes():
-    from repro.launch.mesh import set_mesh
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # 7 not divisible by anything but 1; mesh axes of size 1 divide all
         spec = logical_spec((7, 128), ["batch", "ff"])
         # with axis size 1 the spec is legal either way; just must not crash
