@@ -114,7 +114,8 @@ import jax.numpy as jnp
 from repro.core.aggregation import Aggregator, FedAvg
 from repro.models.fl_models import as_local_step
 from repro.obs.profiling import (STAGE_AGGREGATE, STAGE_GATHER,
-                                 STAGE_LOCAL_SGD, STAGE_UPLOAD, stage)
+                                 STAGE_LOCAL_SGD, STAGE_PREDICT,
+                                 STAGE_SELECT, STAGE_UPLOAD, stage)
 
 BACKENDS = ("xla", "pallas")
 PREFETCH_MODES = ("off", "double_buffer")
@@ -318,17 +319,19 @@ class RoundEngine:
         consumed (tests/test_fused_generic.py)."""
         state: dict = {}
 
-        def call(*args):
-            jitted = state.get("jitted")
-            if jitted is None:
+        def jitted():
+            if "jitted" not in state:
                 argnums = (tuple(donate) if self.donate
                            and jax.default_backend() != "cpu" else ())
-                jitted = state["jitted"] = jax.jit(
-                    fn, donate_argnums=argnums)
+                state["jitted"] = jax.jit(fn, donate_argnums=argnums)
                 call.donate_argnums = argnums
-            return jitted(*args)
+            return state["jitted"]
+
+        def call(*args):
+            return jitted()(*args)
 
         call.donate_argnums = None
+        call.lower = lambda *args: jitted().lower(*args)
         call._fn = fn
         call._donate = tuple(donate)
         return call
@@ -1478,8 +1481,12 @@ class RoundEngine:
         budgeted local SGD + aggregation, and the ValueTracker scatter.
         Zero bytes cross the host boundary inside a block; the caller pulls
         ``stats`` (per-round [block] arrays: dropout, train_loss, assigned,
-        uploaded, true_workload, and the [block, K] cohort ``ids``) once per
-        segment.
+        uploaded, true_workload, the int32 ``local_steps`` counter -- the
+        minibatch steps the cohort was budgeted and trained, ``n_iters``
+        summed over its slots -- and the [block, K] cohort ``ids``) once
+        per segment.  Selection and the value update run under the
+        ``fed.select`` scope, workload prediction and budgets under
+        ``fed.predict`` (``repro.obs.profiling``).
 
         ``cfg`` is duck-typed ``ServerConfig`` (algo / n_selected /
         al_rounds / beta / selection / U / alpha / gamma1 / gamma2 / h_cap /
@@ -1528,9 +1535,9 @@ class RoundEngine:
         existing stats pull — host_syncs_per_round does NOT change — and
         all extras are derived from replicated values, so the sharded
         segment needs no extra collectives.  ``telemetry=False``
-        (default) emits the exact PR-6 stats dict: the traced program is
-        unchanged, keeping untelemetered runs bitwise identical
-        (tests/test_telemetry.py).
+        (default) emits only the base stats dict above, and the extras'
+        code is absent from the traced program; training is bitwise the
+        same either way (tests/test_telemetry.py).
         """
         from repro.core import prediction as pred
         from repro.core.heterogeneity import sample_workloads_device
@@ -1664,11 +1671,12 @@ class RoundEngine:
                 if fm is not None:
                     E_all = apply_availability_stragglers(fm, phases, t,
                                                           E_all)
-                if quarantine:
-                    ids = select(k_sel, values, t,
-                                 eligibility(carry["q_susp"], t))
-                else:
-                    ids = select(k_sel, values, t)
+                with stage(STAGE_SELECT):
+                    if quarantine:
+                        ids = select(k_sel, values, t,
+                                     eligibility(carry["q_susp"], t))
+                    else:
+                        ids = select(k_sel, values, t)
                 E_true = E_all[ids]
                 ovf = (jnp.zeros(ids.shape, bool) if overflow is None
                        else overflow(ids))
@@ -1680,21 +1688,24 @@ class RoundEngine:
                            if fm is not None and fm.corrupts else None)
                 E_obs = (jnp.where(corrupt, jnp.float32(0.0), E_run)
                          if demote else E_run)
-                e_eff, outcome, assigned, L_new, H_new, theta_new = \
-                    pred.workload_update_device(algo, L, H, theta, ids,
-                                                E_obs, **wl_kwargs)
-                if demote and injecting:
-                    # the faulty client doesn't know it will be screened:
-                    # it trains with the UN-demoted budget (same old
-                    # history, real E~) and transmits garbage.  ids are
-                    # unique, so per-row e_eff matches the observed call
-                    # bitwise on every non-corrupt row.
-                    e_train = pred.workload_update_device(
-                        algo, L, H, theta, ids, E_run, **wl_kwargs)[0]
-                else:
-                    e_train = e_eff
-                n = jnp.minimum(sizes[ids], max_n)
-                n_iters = budget_iters(e_train, n, batch_size, max_iters)
+                with stage(STAGE_PREDICT):
+                    e_eff, outcome, assigned, L_new, H_new, theta_new = \
+                        pred.workload_update_device(algo, L, H, theta, ids,
+                                                    E_obs, **wl_kwargs)
+                    if demote and injecting:
+                        # the faulty client doesn't know it will be
+                        # screened: it trains with the UN-demoted budget
+                        # (same old history, real E~) and transmits
+                        # garbage.  ids are unique, so per-row e_eff
+                        # matches the observed call bitwise on every
+                        # non-corrupt row.
+                        e_train = pred.workload_update_device(
+                            algo, L, H, theta, ids, E_run, **wl_kwargs)[0]
+                    else:
+                        e_train = e_eff
+                    n = jnp.minimum(sizes[ids], max_n)
+                    n_iters = budget_iters(e_train, n, batch_size,
+                                           max_iters)
                 data_rng, sub = jax.random.split(carry["data_rng"])
                 new_carry = dict(carry, L=L_new, H=H_new, theta=theta_new,
                                  sel_rng=sel_rng, data_rng=data_rng)
@@ -1735,8 +1746,9 @@ class RoundEngine:
                     # the observed upload set: screened-out rows count as
                     # crashes, bitwise the crash-twin's (n_iters > 0)
                     uploaded = uploaded & ~corrupt
-                values = value_update_device(values, sizes, ids, losses,
-                                             uploaded)
+                with stage(STAGE_SELECT):
+                    values = value_update_device(values, sizes, ids, losses,
+                                                 uploaded)
                 upf = uploaded.astype(jnp.float32)
                 n_up = upf.sum()
                 stats = {
@@ -1753,6 +1765,7 @@ class RoundEngine:
                     "assigned": assigned.mean(),
                     "uploaded": e_eff.mean(),
                     "true_workload": E_true.mean(),
+                    "local_steps": n_iters.sum(dtype=jnp.int32),
                 }
                 if telemetry:
                     # ISSUE 7: device-accumulated extras that ride the

@@ -111,9 +111,12 @@ tests/test_telemetry.py), and with telemetry off the traced programs (and
 therefore the runs) are bitwise identical to untelemetered PR-6 on both
 drivers and both backends.  The host driver computes the same extras in
 numpy with identical binning (``repro.obs.schema.histogram_counts``).
-Stage-level profiler regions (gather / local SGD / upload transform /
-aggregate — ``repro.obs.profiling``) annotate the round pipeline for trace
-capture via ``fl_train --trace-dir``.
+Named ``fed.*`` scopes (predict / select / gather / local SGD / upload
+transform / aggregate — ``repro.obs.profiling``) mark the round's device
+ops; ``segment_stage_map`` maps the compiled segment's instructions to
+them.  The scan loop's host phases run under ``fed.host.*`` spans
+(``host_spans``), and every round's stats count the local-SGD steps it
+trained (``local_steps``).  Capture a trace via ``fl_train --trace-dir``.
 
 Capacity compaction (``ServerConfig.cohort_capacity``, ISSUE 5): how much
 of the cohort each shard actually EXECUTES.  The default "full" runs all
@@ -178,7 +181,9 @@ mitigation never masks a sick federation.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import math
 import time
 from typing import Dict, List, Optional
@@ -197,6 +202,7 @@ from repro.core.selection import (ValueTracker, cohort_overflow,
                                   select_active, select_cohort_device,
                                   value_update_device)
 from repro.data.federated import FederatedDataset
+from repro.obs import profiling
 from repro.obs.schema import (HISTORY_KEYS, LOSS_HIST_BINS, LOSS_HIST_MAX,
                               WORKLOAD_HIST_BINS, RoundRecord,
                               histogram_counts, record_from_row,
@@ -205,6 +211,8 @@ from repro.obs.sinks import NullSink, RingBufferSink, Sink
 
 DRIVERS = ("host", "scan")
 RNG_IMPLS = ("numpy", "device")
+# entries kept in FedSAEServer.host_spans: five phases a block
+HOST_SPAN_LOG = 4096
 
 
 @dataclasses.dataclass
@@ -595,6 +603,9 @@ class FedSAEServer:
         self.eval_fn = make_eval_fn(model)
         self.cohorts: List[np.ndarray] = []   # [K] ids per executed round
         self.host_syncs = 0                   # device->host pulls
+        # the scan loop's host phases, (name, block, t0, t1) on
+        # time.perf_counter (repro.obs.profiling.host_span)
+        self.host_spans = collections.deque(maxlen=HOST_SPAN_LOG)
 
     # ------------------------------------------------------------------
     # telemetry (ISSUE 7): the single record path both drivers share
@@ -843,6 +854,7 @@ class FedSAEServer:
             "assigned": float(np.mean(assigned)),
             "uploaded": float(np.mean(e_eff)),
             "true_workload": float(np.mean(E_true)),
+            "local_steps": int(np.sum(n_iters)),
         }
         if self.engine.screening:
             stats["screened"] = float(bad.sum())
@@ -917,66 +929,102 @@ class FedSAEServer:
             self.q_try = np.asarray(state["q_try"], np.int32)
             self.q_susp = np.asarray(state["q_susp"], np.int32)
 
+    def _segment_args(self, state: Dict, ts) -> tuple:
+        """The scan segment's arguments for the carry ``state`` and the
+        round indices ``ts``."""
+        pk = self.packed
+        args = (state, ts, pk.x, pk.y, pk.offsets, pk.lengths,
+                self._mu_dev, self._sigma_dev)
+        return args if self.residual is None else args + (self.residual,)
+
+    def segment_stage_map(self):
+        """``(module name, {instruction: fed.* stage or None})`` of the
+        compiled scan segment of one full block
+        (``repro.obs.profiling.stage_map``): what the device trace's
+        ``XLA Ops`` events, named by instruction, belong to.  Compiles the
+        program ``_run_scan`` runs, on arguments built by the same code (a
+        compile-cache hit once the segment has run)."""
+        if self.segment_fn is None:
+            raise ValueError("segment_stage_map needs driver='scan'")
+        ts = jnp.arange(self.block_size, dtype=jnp.int32)
+        lowered = self.segment_fn.lower(
+            *self._segment_args(self.device_state(), ts))
+        return profiling.stage_map(lowered.compile().as_text())
+
     def _run_scan(self, T: int, verbose: bool, t_start: int = 0,
                   checkpoint_dir: Optional[str] = None,
                   checkpoint_every: int = 0):
+        """Blocks of ``block_size`` rounds, one dispatch and one host pull
+        each.  Every block runs under a ``fed.block`` step annotation and
+        its host phases under ``fed.host.*`` spans, logged to
+        ``host_spans``; each phase is opened every block, done or not."""
         cfg = self.cfg
         tx, ty = jnp.asarray(self.ds.test_x), jnp.asarray(self.ds.test_y)
         state = self.device_state()
-        pk = self.packed
         t0 = t_start
         while t0 < T:
             b = min(self.block_size, T - t0)
-            blk_start = time.perf_counter()
-            ts = jnp.arange(t0, t0 + b, dtype=jnp.int32)
-            if self.residual is not None:
-                state, self.residual, stats = self.segment_fn(
-                    state, ts, pk.x, pk.y, pk.offsets, pk.lengths,
-                    self._mu_dev, self._sigma_dev, self.residual)
-            else:
-                state, stats = self.segment_fn(
-                    state, ts, pk.x, pk.y, pk.offsets, pk.lengths,
-                    self._mu_dev, self._sigma_dev)
-            stats = jax.device_get(stats)   # the block's single host pull
-            self.host_syncs += 1
-            wall = time.perf_counter() - blk_start
-            self.cohorts.extend(np.asarray(stats["ids"]))
-            # eval at most once per block (with the block-end params), and
-            # only when a round inside the block was due per eval_every
-            due = (t0 + b == T) or any(
-                (t0 + i) % cfg.eval_every == 0 for i in range(b))
-            prev = self._records.last
-            prev_acc = prev.acc if prev is not None else float("nan")
-            acc, tl = prev_acc, float("nan")
-            if due:
-                acc, tl = self.eval_fn(state["params"], tx, ty)
-                acc, tl = float(acc), float(tl)
-                self.host_syncs += 1    # ...plus the eval readback
-            recs = records_from_block_stats(stats, t0, b)
-            for i, rec in enumerate(recs):
-                last = i == b - 1
-                rec.acc = acc if last else prev_acc
-                rec.test_loss = tl if last else float("nan")
-                rec.wall_time_s = wall / b
-                if self.telemetry and self.mesh is not None:
-                    rec.lane_occupancy = self._lane_occupancy(
-                        np.asarray(stats["ids"])[i])
-                self._emit_round(rec)
-            if verbose:
-                print(self._progress_line(
-                    f"{cfg.algo}/scan", f"rounds {t0:3d}-{t0 + b - 1:3d}",
-                    acc, recs[-1].dropout, recs[-1].train_loss,
-                    float(np.sum(stats["overflowed"]))))
-            t0 += b
-            if checkpoint_dir and (
-                    (checkpoint_every > 0 and t0 % checkpoint_every == 0)
-                    or t0 == T):
-                # the scan driver checkpoints at block boundaries only;
-                # align checkpoint_every with block_size for a resumed
-                # trace whose eval cadence matches the uninterrupted run
-                from repro.checkpoint import save_server_state
-                self._absorb_state(state)
-                save_server_state(self, checkpoint_dir, t0)
+            blk = t0 // self.block_size
+            span = functools.partial(profiling.host_span,
+                                     log=self.host_spans, block=blk)
+            with jax.profiler.StepTraceAnnotation(profiling.HOST_BLOCK,
+                                                  step_num=blk):
+                blk_start = time.perf_counter()
+                with span(profiling.HOST_DISPATCH):
+                    ts = jnp.arange(t0, t0 + b, dtype=jnp.int32)
+                    out = self.segment_fn(*self._segment_args(state, ts))
+                    if self.residual is not None:
+                        state, self.residual, stats = out
+                    else:
+                        state, stats = out
+                with span(profiling.HOST_PULL):
+                    # the block's single host pull
+                    stats = jax.device_get(stats)
+                    self.host_syncs += 1
+                wall = time.perf_counter() - blk_start
+                with span(profiling.HOST_EVAL):
+                    # eval at most once per block (with the block-end
+                    # params), and only when a round inside the block was
+                    # due per eval_every
+                    due = (t0 + b == T) or any(
+                        (t0 + i) % cfg.eval_every == 0 for i in range(b))
+                    prev = self._records.last
+                    prev_acc = prev.acc if prev is not None else float("nan")
+                    acc, tl = prev_acc, float("nan")
+                    if due:
+                        acc, tl = self.eval_fn(state["params"], tx, ty)
+                        acc, tl = float(acc), float(tl)
+                        self.host_syncs += 1    # ...plus the eval readback
+                with span(profiling.HOST_RECORDS):
+                    self.cohorts.extend(np.asarray(stats["ids"]))
+                    recs = records_from_block_stats(stats, t0, b)
+                    for i, rec in enumerate(recs):
+                        last = i == b - 1
+                        rec.acc = acc if last else prev_acc
+                        rec.test_loss = tl if last else float("nan")
+                        rec.wall_time_s = wall / b
+                        if self.telemetry and self.mesh is not None:
+                            rec.lane_occupancy = self._lane_occupancy(
+                                np.asarray(stats["ids"])[i])
+                        self._emit_round(rec)
+                    if verbose:
+                        print(self._progress_line(
+                            f"{cfg.algo}/scan",
+                            f"rounds {t0:3d}-{t0 + b - 1:3d}", acc,
+                            recs[-1].dropout, recs[-1].train_loss,
+                            float(np.sum(stats["overflowed"]))))
+                t0 += b
+                with span(profiling.HOST_CHECKPOINT):
+                    if checkpoint_dir and (
+                            (checkpoint_every > 0
+                             and t0 % checkpoint_every == 0) or t0 == T):
+                        # the scan driver checkpoints at block boundaries
+                        # only; align checkpoint_every with block_size for
+                        # a resumed trace whose eval cadence matches the
+                        # uninterrupted run
+                        from repro.checkpoint import save_server_state
+                        self._absorb_state(state)
+                        save_server_state(self, checkpoint_dir, t0)
         self._absorb_state(state)
         return self.history
 

@@ -27,7 +27,6 @@ from repro.kernels.flash_attention import (flash_attention_bwd,
                                            flash_attention_fwd)
 from repro.kernels.fused_xent import fused_softmax_xent_fwd
 from repro.kernels.selective_scan import selective_scan_fwd
-from repro.obs.profiling import annotate
 
 
 def _interpret() -> bool:
@@ -126,7 +125,6 @@ fused_softmax_xent.defvjp(_fx_fwd, _fx_bwd)
 # ---------------------------------------------------------------------------
 
 
-@annotate("fed.gather.pallas")
 def fed_cohort_gather(flat_x, flat_y, starts, ns, max_n: int):
     """Fused gather+mask over the packed federation (see fed_gather.py).
 
@@ -136,7 +134,6 @@ def fed_cohort_gather(flat_x, flat_y, starts, ns, max_n: int):
                                  interpret=_interpret())
 
 
-@annotate("fed.local_sgd.pallas")
 def fed_local_sgd_mclr(x, y, idx, w0, b0, ns, n_iters, lr: float,
                        prox_mu: float = 0.0):
     """Fused masked budgeted MCLR local SGD (see fed_local_sgd.py).
@@ -147,7 +144,6 @@ def fed_local_sgd_mclr(x, y, idx, w0, b0, ns, n_iters, lr: float,
                                   interpret=_interpret())
 
 
-@annotate("fed.local_sgd_dense.pallas")
 def fed_local_sgd_dense(x, y, idx, w1, b1, w2, b2, ns, n_iters, lr: float,
                         prox_mu: float = 0.0):
     """Fused masked budgeted dense-MLP local SGD (see fed_local_sgd_dense.py).
@@ -180,7 +176,6 @@ def fused_sgd_eligible(step, sampling: str) -> bool:
             and getattr(step, "kind", None) in FUSED_SGD_KINDS)
 
 
-@annotate("fed.upload_transform.pallas")
 def fed_compress_topk_q8(ef, k: int):
     """Fused top-k + int8 upload compression over per-client error-feedback
     delta rows (see fed_compress.py).  Bitwise-identical to the ref twin.

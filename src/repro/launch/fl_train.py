@@ -369,8 +369,9 @@ def main():
                          "host syncs are unchanged")
     ap.add_argument("--trace-dir", default=None,
                     help="capture a jax.profiler trace of the run into this "
-                         "directory (TensorBoard/perfetto); the four round "
-                         "pipeline stages appear as fed.* regions")
+                         "directory (TensorBoard/perfetto); the scan driver's "
+                         "blocks appear as fed.block steps and its host "
+                         "phases as fed.host.* spans (docs/telemetry.md)")
     ap.add_argument("--quiet", action="store_true",
                     help="suppress per-round/block progress lines (the "
                          "final summary still prints)")

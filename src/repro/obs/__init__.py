@@ -6,8 +6,10 @@ Structured per-round observability for every training path in the repo:
              shared row->record construction path, histogram geometry
   sinks      pluggable record consumers: JSONL file, in-memory ring
              buffer, null, tee
-  profiling  stage-level profiler regions (gather / local SGD / upload
-             transform / aggregate) + trace capture
+  profiling  the device program's ``fed.*`` stage scopes (predict /
+             select / gather / local SGD / upload transform / aggregate),
+             their map from compiled HLO, the scan loop's ``fed.host.*``
+             host spans, and trace capture
   report     markdown straggler/health report renderer
              (CLI: scripts/fl_report.py)
 
@@ -23,9 +25,10 @@ from repro.obs.schema import (HISTORY_KEYS, LOSS_HIST_BINS, LOSS_HIST_MAX,
                               record_from_row, records_from_block_stats)
 from repro.obs.sinks import (JsonlSink, NullSink, RingBufferSink, Sink,
                              TeeSink)
-from repro.obs.profiling import (STAGE_AGGREGATE, STAGE_GATHER,
-                                 STAGE_LOCAL_SGD, STAGE_UPLOAD, annotate,
-                                 stage, trace_if)
+from repro.obs.profiling import (HOST_PHASES, STAGE_AGGREGATE,
+                                 STAGE_GATHER, STAGE_LOCAL_SGD,
+                                 STAGE_PREDICT, STAGE_SELECT, STAGE_UPLOAD,
+                                 host_span, stage, stage_map, trace_if)
 from repro.obs.report import client_reliability, render_report
 
 __all__ = [
@@ -33,7 +36,8 @@ __all__ = [
     "RoundRecord", "SchemaError", "histogram_counts", "read_jsonl",
     "record_from_row", "records_from_block_stats",
     "JsonlSink", "NullSink", "RingBufferSink", "Sink", "TeeSink",
-    "STAGE_AGGREGATE", "STAGE_GATHER", "STAGE_LOCAL_SGD", "STAGE_UPLOAD",
-    "annotate", "stage", "trace_if",
+    "HOST_PHASES", "STAGE_AGGREGATE", "STAGE_GATHER", "STAGE_LOCAL_SGD",
+    "STAGE_PREDICT", "STAGE_SELECT", "STAGE_UPLOAD",
+    "host_span", "stage", "stage_map", "trace_if",
     "client_reliability", "render_report",
 ]

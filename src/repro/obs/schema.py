@@ -8,7 +8,9 @@ round-trips bit-exactly).  Records compare NaN-aware (``NaN == NaN`` within
 a record), so ``write -> read -> equality`` is a clean test invariant.
 
 Scalar fields (always present; NaN when unknown) mirror the server's
-long-standing ``history`` keys; the OPTIONAL fields carry the telemetry
+``history`` keys; ``local_steps`` counts the minibatch steps the round's
+cohort was budgeted and trained (on both server drivers, with or without
+telemetry).  The OPTIONAL fields carry the telemetry
 extras that only exist when on-device metric accumulation is enabled
 (``RoundEngine.make_segment_fn(telemetry=True)`` / a server with a sink):
 
@@ -49,7 +51,8 @@ WORKLOAD_HIST_BINS = 16  # over [0, h_cap) uploaded epochs
 
 # scalar per-round metrics, in the order the legacy history dict carried
 HISTORY_KEYS = ("acc", "test_loss", "train_loss", "dropout", "assigned",
-                "uploaded", "true_workload", "overflowed", "dropped")
+                "uploaded", "true_workload", "overflowed", "dropped",
+                "local_steps")
 
 _FLOAT_FIELDS = ("wall_time_s",) + HISTORY_KEYS
 _OPT_LIST_FIELDS = ("ids", "client_uploaded", "loss_hist", "workload_hist",
@@ -81,6 +84,7 @@ class RoundRecord:
     true_workload: float = dataclasses.field(default_factory=_nan)
     overflowed: float = dataclasses.field(default_factory=_nan)
     dropped: float = dataclasses.field(default_factory=_nan)
+    local_steps: float = dataclasses.field(default_factory=_nan)
     # telemetry extras (None when metric accumulation was off)
     ids: Optional[List[int]] = None
     client_uploaded: Optional[List[int]] = None

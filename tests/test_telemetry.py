@@ -18,9 +18,11 @@ Four layers of proof:
     (sharded lane-occupancy extras are covered at S=1 always and S=8 under
     the CI multi-device job).
 """
+import contextlib
 import json
 import math
 import os
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +30,7 @@ import numpy as np
 import pytest
 
 from repro.core import FedSAEServer, HeterogeneitySim, ServerConfig
+from repro.core import engine
 from repro.core.engine import _device_hist
 from repro.data.federated import make_femnist_like
 from repro.models.fl_models import make_mclr
@@ -35,6 +38,7 @@ from repro.obs import (HISTORY_KEYS, LOSS_HIST_BINS, LOSS_HIST_MAX,
                        JsonlSink, NullSink, RingBufferSink, RoundRecord,
                        SchemaError, histogram_counts, read_jsonl,
                        record_from_row, render_report)
+from repro.obs import profiling
 
 N_CLIENTS = 24
 DIM = 16
@@ -69,12 +73,31 @@ def _server(fed, driver, backend="xla", shards=0, sink=None, telemetry=None):
 _RUNS = {}
 
 
-def _run(fed, driver, backend="xla", shards=0, telemetry=False):
-    """Completed run, memoized per configuration (params, history, server)."""
-    key = (driver, backend, shards, telemetry)
+@contextlib.contextmanager
+def _no_scope(name):
+    yield
+
+
+@contextlib.contextmanager
+def _no_span(name, log, block):
+    yield
+
+
+def _run(fed, driver, backend="xla", shards=0, telemetry=False,
+         scoped=True):
+    """Completed run, memoized per configuration (params, history, server).
+    ``scoped=False`` builds and runs the server with the ``fed.*`` stage
+    scopes and the host loop's spans stripped out."""
+    key = (driver, backend, shards, telemetry, scoped)
     if key not in _RUNS:
-        srv = _server(fed, driver, backend, shards, telemetry=telemetry)
-        srv.run()
+        with contextlib.ExitStack() as stack:
+            if not scoped:
+                stack.enter_context(mock.patch.object(engine, "stage",
+                                                      _no_scope))
+                stack.enter_context(mock.patch.object(
+                    profiling, "host_span", _no_span))
+            srv = _server(fed, driver, backend, shards, telemetry=telemetry)
+            srv.run()
         _RUNS[key] = srv
     return _RUNS[key]
 
@@ -160,15 +183,25 @@ def test_telemetry_is_numerically_inert(fed, driver, backend):
     """Metric accumulation must not perturb training: final params and the
     history view are BITWISE identical with telemetry on vs off (and the
     off program is the unchanged untelemetered one — the extras are gated
-    out of the traced stats entirely)."""
+    out of the traced stats entirely).  The same holds for the ``fed.*``
+    stage scopes and the host loop's ``fed.host.*`` spans: a run with
+    them stripped out is bitwise the instrumented one."""
     off = _run(fed, driver, backend, telemetry=False)
     on = _run(fed, driver, backend, telemetry=True)
-    for a, b in zip(jax.tree.leaves(off.params), jax.tree.leaves(on.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    ha, hb = off.history, on.history
-    assert list(ha) == list(hb) == list(HISTORY_KEYS)
-    for k in ha:
-        np.testing.assert_array_equal(np.asarray(ha[k]), np.asarray(hb[k]))
+    bare = _run(fed, driver, backend, telemetry=False, scoped=False)
+    for other in (on, bare):
+        for a, b in zip(jax.tree.leaves(off.params),
+                        jax.tree.leaves(other.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        ha, hb = off.history, other.history
+        assert list(ha) == list(hb) == list(HISTORY_KEYS)
+        for k in ha:
+            np.testing.assert_array_equal(np.asarray(ha[k]),
+                                          np.asarray(hb[k]))
+    assert all(s > 0 for s in off.history["local_steps"])
+    assert not bare.host_spans
+    if driver == "scan":
+        assert len(off.host_spans) == 5 * ROUNDS // BLOCK
     # ...and the on-run actually recorded the extras
     for rec in on._records.records:
         assert rec.client_uploaded is not None
