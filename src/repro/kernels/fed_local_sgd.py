@@ -1,14 +1,16 @@
-"""Pallas fused masked local-SGD kernel for the MCLR federated round.
+"""Pallas fused budgeted local-SGD kernel for the MCLR federated round.
 
 The XLA engine runs each client's budgeted SGD as a ``lax.scan`` whose carry
 (the full parameter pytree) round-trips through HBM every iteration, vmapped
-over the cohort.  This kernel runs the whole ``max_iters`` budget for one
-client per grid step inside a single ``pallas_call``: the client's padded
+over the cohort.  This kernel runs one client's whole budgeted local SGD
+per grid step inside a single ``pallas_call``: the client's padded
 shard and the global MCLR params are staged into VMEM once, the parameters
 live in VMEM scratch across the ``fori_loop`` (no per-iteration carry
-round-trip), and FedSAE's heterogeneous budgets stay uniform control flow —
-every client executes ``max_iters`` slots, updates masked by
-``i < n_iters_k`` exactly like the scan path.
+round-trip).  The loop's trip count is the lane's own budget, read from the
+scalar-prefetched ``n_iters_k`` (clamped to ``max_iters``), so FedSAE's
+heterogeneous budgets cost only the steps they grant: a lane walks its
+``n_iters_k`` slots, not all ``max_iters``, and never reads the minibatch
+rows past its budget.
 
 The grid is the leading cohort-block axis of the inputs: the full cohort
 ``K``, or — under capacity-compacted sharded execution (ISSUE 5) — the
@@ -45,7 +47,9 @@ def _sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref, w0_ref, b0_ref,
                 lr: float, prox_mu: float):
     k = pl.program_id(0)
     nk_safe = jnp.maximum(ns_ref[k], 1)
-    iters = iters_ref[k]
+    # the lane's own trip count; clamped because a row read of idx_ref past
+    # max_iters is not checked on the chip
+    iters = jnp.clip(iters_ref[k], 0, max_iters)
 
     w_s[...] = w0_ref[...].astype(jnp.float32)
     b_s[...] = b0_ref[...].astype(jnp.float32)
@@ -60,8 +64,7 @@ def _sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref, w0_ref, b0_ref,
              < nk_safe).astype(jnp.float32)                # [B, 1]
     bsum = jnp.maximum(bmask.sum(), 1.0)
 
-    def body(i, carry):
-        loss_sum, cnt = carry
+    def body(i, loss_sum):
         idx_row = idx_ref[0, pl.ds(i, 1), :].reshape(B, 1)     # [B, 1]
         sel = ((npos == idx_row).astype(jnp.float32)) * bmask  # [B, max_n]
         xb = jnp.dot(sel, x, preferred_element_type=jnp.float32)   # [B, d]
@@ -84,17 +87,16 @@ def _sgd_kernel(ns_ref, iters_ref, x_ref, y_ref, idx_ref, w0_ref, b0_ref,
                                            + jnp.sum(db * db))
             gw = gw + prox_mu * dw
             gb = gb + prox_mu * db
-        active = (i < iters).astype(jnp.float32)
-        w_s[...] = w - lr * active * gw
-        b_s[...] = b - lr * active * gb
-        return loss_sum + loss * active, cnt + active
+        w_s[...] = w - lr * gw
+        b_s[...] = b - lr * gb
+        return loss_sum + loss
 
-    loss_sum, cnt = jax.lax.fori_loop(
-        0, max_iters, body, (jnp.float32(0.0), jnp.float32(0.0)))
+    loss_sum = jax.lax.fori_loop(0, iters, body, jnp.float32(0.0))
     w_ref[0] = w_s[...].astype(w_ref.dtype)
     b_ref[0] = b_s[...].astype(b_ref.dtype)
     # iid loss semantics: mean minibatch loss over executed iterations
-    loss_ref[...] = jnp.full(loss_ref.shape, loss_sum / jnp.maximum(cnt, 1.0))
+    cnt = jnp.maximum(iters, 1).astype(jnp.float32)
+    loss_ref[...] = jnp.full(loss_ref.shape, loss_sum / cnt)
 
 
 def fed_local_sgd_mclr_fwd(x, y, idx, w0, b0, ns, n_iters, *, lr: float,

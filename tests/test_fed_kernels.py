@@ -117,15 +117,34 @@ def test_gather_handles_higher_rank_features():
 # ---------------------------------------------------------------------------
 
 
-def test_local_sgd_kernel_matches_ref_oracle():
-    rng = np.random.default_rng(2)
-    K, max_n, d, C, max_iters, B = 3, 12, 6, 4, 5, 4
+# (max_iters, ns, n_iters): the lanes' client sizes and budgets
+BUDGET_SPREADS = {
+    # full / partial / zero budgets over full / ragged / empty clients
+    "three_lanes": (5, [12, 7, 0], [5, 3, 0]),
+    # every budget from 0 to max_iters, sizes from 1 to max_n
+    "zero_to_max": (12, [12, 1, 9, 4, 12, 7, 2, 12],
+                    [0, 1, 2, 5, 7, 11, 12, 12]),
+    # FedSAE's case: most of the slot grid past the budgets
+    "short_budgets": (16, [12, 3, 8, 12, 5, 10], [1, 0, 2, 3, 1, 16]),
+}
+
+
+def _sgd_case(seed, K, max_n, d, C, max_iters, B):
+    rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(K, max_n, d)), jnp.float32)
     y = jnp.asarray(rng.integers(0, C, (K, max_n)), jnp.int32)
-    ns = jnp.asarray([12, 7, 0], jnp.int32)       # full / ragged / empty
-    n_iters = jnp.asarray([5, 3, 0], jnp.int32)   # full / partial / zero
     idx = jnp.asarray(rng.integers(0, 7, (K, max_iters, B)), jnp.int32)
     w0 = jnp.asarray(rng.normal(size=(d, C)) * 0.1, jnp.float32)
+    return rng, x, y, idx, w0
+
+
+@pytest.mark.parametrize("spread", sorted(BUDGET_SPREADS))
+def test_local_sgd_kernel_matches_ref_oracle(spread):
+    max_iters, ns, n_iters = BUDGET_SPREADS[spread]
+    K, max_n, d, C, B = len(ns), 12, 6, 4, 4
+    _, x, y, idx, w0 = _sgd_case(2, K, max_n, d, C, max_iters, B)
+    ns = jnp.asarray(ns, jnp.int32)
+    n_iters = jnp.asarray(n_iters, jnp.int32)
     b0 = jnp.zeros(C, jnp.float32)
     for prox_mu in (0.0, 0.2):
         w_k, b_k, losses = ops.fed_local_sgd_mclr(
@@ -135,6 +154,43 @@ def test_local_sgd_kernel_matches_ref_oracle():
         np.testing.assert_allclose(w_k, wr, rtol=RTOL, atol=ATOL)
         np.testing.assert_allclose(b_k, br, rtol=RTOL, atol=ATOL)
         np.testing.assert_allclose(losses, lr_, rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("prox_mu", [0.0, 0.2])
+def test_local_sgd_ignores_idx_rows_past_each_budget(prox_mu):
+    """A lane runs its own n_iters_k steps: the minibatch rows past its
+    budget are never read, so re-drawing them leaves every output bitwise
+    the same (budgets 0, 1, partial and max_iters)."""
+    K, max_n, d, C, max_iters, B = 4, 10, 5, 3, 6, 3
+    rng, x, y, idx, w0 = _sgd_case(5, K, max_n, d, C, max_iters, B)
+    b0 = jnp.asarray(rng.normal(size=C) * 0.1, jnp.float32)
+    ns = jnp.asarray([10, 6, 9, 10], jnp.int32)
+    n_iters = np.array([0, 1, 4, max_iters], np.int32)
+    past = np.arange(max_iters)[None, :] >= n_iters[:, None]   # [K, iters]
+    redrawn = np.where(past[:, :, None],
+                       rng.integers(0, max_n, (K, max_iters, B)),
+                       np.asarray(idx))
+    assert (redrawn != np.asarray(idx))[past].any()
+    args = (w0, b0, ns, jnp.asarray(n_iters))
+    kw = dict(lr=0.2, prox_mu=prox_mu)
+    _tree_equal(
+        ops.fed_local_sgd_mclr(x, y, idx, *args, **kw),
+        ops.fed_local_sgd_mclr(x, y, jnp.asarray(redrawn, jnp.int32),
+                               *args, **kw))
+
+
+def test_local_sgd_budget_above_max_iters_runs_max_iters():
+    """The trip count is clamped to max_iters inside the kernel: a budget
+    past the slot grid gives bitwise the output of a max_iters budget."""
+    K, max_n, d, C, max_iters, B = 3, 8, 4, 3, 5, 2
+    rng, x, y, idx, w0 = _sgd_case(6, K, max_n, d, C, max_iters, B)
+    b0 = jnp.asarray(rng.normal(size=C) * 0.1, jnp.float32)
+    ns = jnp.asarray([8, 5, 8], jnp.int32)
+    over = jnp.asarray([max_iters + 1, 3, 10 * max_iters], jnp.int32)
+    clamped = jnp.minimum(over, max_iters)
+    _tree_equal(
+        ops.fed_local_sgd_mclr(x, y, idx, w0, b0, ns, over, lr=0.1),
+        ops.fed_local_sgd_mclr(x, y, idx, w0, b0, ns, clamped, lr=0.1))
 
 
 def test_local_sgd_zero_budget_returns_globals_and_zero_loss():

@@ -15,6 +15,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.data.federated import ROW_ALIGN
@@ -96,4 +97,20 @@ KERNELS = {
 def test_kernel_compiles_for_v5e_at_paper_shapes(one_chip, name):
     fn, specs = KERNELS[name]
     compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_local_sgd_compiles_with_budgets_from_zero_to_max_iters(one_chip):
+    """Each lane's loop runs to its own budget, read at run time from the
+    scalar-prefetched n_iters: Mosaic lowers the loop with a dynamic trip
+    count, here for a budget vector that holds 0 and MAX_ITERS."""
+    fn, specs = KERNELS["fed_local_sgd"]
+    budgets = np.linspace(0, MAX_ITERS, K).round().astype(np.int32)
+    assert budgets[0] == 0 and budgets[-1] == MAX_ITERS
+
+    def with_budgets(*args):
+        return fn(*args, jnp.asarray(budgets))
+
+    compiled = jax.jit(with_budgets).lower(
+        *_shapes(one_chip, *specs[:-1])).compile()
     assert "tpu_custom_call" in compiled.as_text()
