@@ -194,6 +194,22 @@ def budget_iters(e_eff, n, batch_size: int, max_iters: int):
     return jnp.minimum(jnp.round(e * tau), max_iters).astype(jnp.int32)
 
 
+def draw_minibatch_idx(keys, n, max_iters: int, batch_size: int):
+    """[K, max_iters, B] iid minibatch indices for the fused kernels, lane k
+    drawing ``randint(keys[k], (max_iters, B), 0, max(n_k, 1))``.
+
+    Each lane draws one flat ``max_iters * B`` vector and reshapes it:
+    threefry's bits depend on the key, the flat position and the count,
+    not on the shape, so the indices are bitwise the ``(max_iters, B)``
+    draw's (the XLA iid walk's and the reference's).  The flat draw is
+    lane-dense on a TPU; a ``[..., B]`` draw with B = 10 fills 10 of 128
+    lanes, and its bits and remainders cost 12.8x the vector work.
+    """
+    flat = jax.vmap(lambda key, nk: jax.random.randint(
+        key, (max_iters * batch_size,), 0, jnp.maximum(nk, 1)))(keys, n)
+    return flat.reshape(keys.shape[0], max_iters, batch_size)
+
+
 class RoundEngine:
     """Shared executor for federated rounds with pluggable aggregation.
 
@@ -769,12 +785,11 @@ class RoundEngine:
                    batch_size: int, max_iters: int):
         """Budgeted local SGD through the fused kernel for ``model.kind``
         (fed_local_sgd for MCLR, fed_local_sgd_dense for the two-layer MLP
-        family — dispatch, not assumption).  Minibatch indices are drawn
-        with the exact randint call the XLA iid path uses, so the backends
-        see bit-identical batches."""
+        family — dispatch, not assumption).  Minibatch indices are the
+        XLA iid path's randint draw, bit for bit (``draw_minibatch_idx``),
+        so the backends see identical batches."""
         from repro.kernels import ops as kops
-        idx = jax.vmap(lambda key, nk: jax.random.randint(
-            key, (max_iters, batch_size), 0, jnp.maximum(nk, 1)))(keys, n)
+        idx = draw_minibatch_idx(keys, n, max_iters, batch_size)
         kind = getattr(model, "kind", None)
         if kind == "mlp":
             w1_k, b1_k, w2_k, b2_k, losses = kops.fed_local_sgd_dense(

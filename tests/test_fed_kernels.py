@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.core.aggregation import FedAvg
-from repro.core.engine import RoundEngine
+from repro.core.engine import RoundEngine, draw_minibatch_idx
 from repro.data.federated import make_femnist_like
 from repro.kernels import ops, ref
 from repro.models.fl_models import make_lstm, make_mclr
@@ -209,6 +209,38 @@ def test_local_sgd_zero_budget_returns_globals_and_zero_loss():
         np.testing.assert_array_equal(np.asarray(w_k[k]), np.asarray(w0))
         np.testing.assert_array_equal(np.asarray(b_k[k]), np.asarray(b0))
     np.testing.assert_array_equal(np.asarray(losses), np.zeros(2))
+
+
+# (K, max_iters, B, client sizes): the two benchmark cells' shapes, a draw
+# shorter than one 128-lane row, B = 1, and a cohort of empty lanes
+DRAW_SHAPES = {
+    "fassa_k30": (30, 960, 10, None),
+    "fedavg_k100": (100, 40, 10, None),
+    "under_128": (5, 12, 10, None),
+    "batch_1": (6, 37, 1, None),
+    "empty_lanes": (4, 9, 3, [0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("partitionable", [True, False])
+@pytest.mark.parametrize("shape", sorted(DRAW_SHAPES))
+def test_flat_minibatch_draw_is_bitwise_the_shaped_draw(shape, partitionable):
+    """The fused kernels' lane-dense index draw gives bitwise the
+    [max_iters, B] randint draw the XLA iid walk and the reference make,
+    under either threefry mode; lanes with n = 0 draw from [0, 1)."""
+    K, max_iters, B, sizes = DRAW_SHAPES[shape]
+    if sizes is None:   # a mix of empty, small and full clients
+        sizes = np.random.default_rng(K).integers(0, 401, K)
+        sizes[0] = 0
+    n = jnp.asarray(sizes, jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(2_718_281_829), K)
+    with jax.threefry_partitionable(partitionable):
+        got = draw_minibatch_idx(keys, n, max_iters, B)
+        want = jax.vmap(lambda key, nk: jax.random.randint(
+            key, (max_iters, B), 0, jnp.maximum(nk, 1)))(keys, n)
+    assert got.shape == (K, max_iters, B) and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got)[np.asarray(n) == 0].any()
 
 
 # ---------------------------------------------------------------------------

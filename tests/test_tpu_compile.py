@@ -12,12 +12,14 @@ description at collection time would give workers different tests.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.engine import draw_minibatch_idx
 from repro.data.federated import ROW_ALIGN
 from repro.kernels.fed_compress import fed_compress_topk_q8_fwd
 from repro.kernels.fed_gather import fed_cohort_gather_fwd
@@ -114,3 +116,35 @@ def test_local_sgd_compiles_with_budgets_from_zero_to_max_iters(one_chip):
     compiled = jax.jit(with_budgets).lower(
         *_shapes(one_chip, *specs[:-1])).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _draw_into_local_sgd(draw):
+    """``draw(keys, ns)``'s minibatch indices feeding fed_local_sgd, the
+    way the engine's fused round calls them."""
+    def round_fn(rng, x, y, w0, b0, ns, n_iters):
+        idx = draw(jax.random.split(rng, K), ns)
+        return fed_local_sgd_mclr_fwd(x, y, idx, w0, b0, ns, n_iters,
+                                      lr=0.03, interpret=False)
+    return round_fn
+
+
+def test_minibatch_draw_compiles_lane_dense(one_chip):
+    """The engine draws the kernel's [K, max_iters, B] indices flat, so the
+    random bits and remainders run on full 128-lane rows; a draw shaped
+    [..., B] with B = 10 is padded to 128 lanes.  No fusion may produce the
+    padded index array (a reshape or copy into it is the kernel's relayout);
+    the shaped draw, compiled the same way, does."""
+    padded = re.compile(
+        r"= s32\[%d,%d,%d\]\{[^}]*\} fusion\(" % (K, MAX_ITERS, B))
+    specs = [((2,), jnp.uint32)] + KERNELS["fed_local_sgd"][1][:2] + \
+        KERNELS["fed_local_sgd"][1][3:]
+    shaped = jax.vmap(lambda key, nk: jax.random.randint(
+        key, (MAX_ITERS, B), 0, jnp.maximum(nk, 1)))
+    texts = {}
+    for name, draw in (("flat", lambda keys, ns: draw_minibatch_idx(
+            keys, ns, MAX_ITERS, B)), ("shaped", shaped)):
+        texts[name] = jax.jit(_draw_into_local_sgd(draw)).lower(
+            *_shapes(one_chip, *specs)).compile().as_text()
+        assert "tpu_custom_call" in texts[name]
+    assert padded.search(texts["shaped"])
+    assert not padded.search(texts["flat"])
